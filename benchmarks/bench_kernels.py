@@ -49,8 +49,7 @@ def run() -> None:
     vals = jnp.asarray(rng.normal(0, 1, (R, K)), jnp.float32)
     cols = jnp.asarray(rng.integers(-1, G, (R, K)), jnp.int32)
     us = _time(lambda v_, c_: ref.ell_to_dense_ref(v_, c_, G), vals, cols)
-    out_i = ell_to_dense(vals, cols, n_cols=G, block_rows=8, block_cols=256,
-                         interpret=True)
+    out_i = ell_to_dense(vals, cols, n_cols=G, block_cols=256, interpret=True)
     err = float(jnp.max(jnp.abs(out_i - ref.ell_to_dense_ref(vals, cols, G))))
     emit("kernel_ell_to_dense", us, f"ref_path_us={us:.0f};interp_max_err={err:.2e}")
 

@@ -40,8 +40,9 @@ class CSRBatch:
 
     Supports row indexing so it can flow through ScDataset's in-memory
     reshuffle/batching (Algorithm 1 lines 9–10) without densification;
-    ``to_dense`` is the fetch_transform hot-spot (Pallas kernel on TPU —
-    see repro.kernels.csr_to_dense).
+    ``to_dense`` is the fetch_transform hot-spot.  Densify runs on the host
+    today; the on-chip path (``to_ell`` + repro.kernels.csr_to_dense) is
+    checked on the TPU only by ``chip_smoke.py``.
     """
 
     data: np.ndarray  # (nnz,) float32
@@ -86,17 +87,25 @@ class CSRBatch:
         """Pad to ELL format (rows, K): (values, cols) with col=-1 padding.
 
         This is the TPU-friendly layout consumed by the csr_to_dense Pallas
-        kernel (see DESIGN.md §2).
+        kernel.  ``k_max=None`` takes K from this batch's longest row; pass
+        the store's :attr:`ShardedCSRStore.ell_width` instead so the device
+        shape stays fixed across batches.  A ``k_max`` below the longest
+        row raises: a narrower slab would drop nonzeros.
         """
         lens = np.diff(self.indptr).astype(np.int64)
-        K = int(lens.max() if k_max is None else k_max)
+        longest = int(lens.max()) if len(lens) else 0
+        K = longest if k_max is None else int(k_max)
+        if K < longest:
+            raise ValueError(
+                f"k_max={K} is below the batch's longest row ({longest} "
+                "nonzeros): the ELL slab would drop data"
+            )
         r = len(self)
         vals = np.zeros((r, K), dtype=np.float32)
         cols = np.full((r, K), -1, dtype=np.int32)
-        row_ids = np.repeat(np.arange(r), np.minimum(lens, K))
-        # within-row positions
-        pos = _within_run_positions(np.minimum(lens, K))
-        src = _ranges_concat(self.indptr[:-1], np.minimum(lens, K))
+        row_ids = np.repeat(np.arange(r), lens)
+        pos = _within_run_positions(lens)
+        src = _ranges_concat(self.indptr[:-1], lens)
         vals[row_ids, pos] = self.data[src]
         cols[row_ids, pos] = self.indices[src]
         return vals, cols
@@ -124,6 +133,14 @@ def _ranges_concat(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:
     if len(starts_nz) > 1:
         out[first_pos[1:]] = starts_nz[1:] - prev_end + 1
     return np.cumsum(out)
+
+
+def _ell_width(row_nnz: np.ndarray) -> int:
+    """ELL K that fits every row: the largest row nonzero count, rounded up
+    to a whole number of 128-wide TPU lanes.  One K per dataset keeps the
+    device program's shapes fixed across batches (no recompiles)."""
+    longest = int(row_nnz.max()) if len(row_nnz) else 0
+    return max(1, -(-longest // 128)) * 128
 
 
 def _within_run_positions(lens: np.ndarray) -> np.ndarray:
@@ -166,6 +183,11 @@ class CSRStore:
     @property
     def avg_row_bytes(self) -> float:
         return self._row_bytes
+
+    @property
+    def ell_width(self) -> int:
+        """Dataset-level ELL K (see :func:`_ell_width`)."""
+        return _ell_width(np.diff(self._indptr))
 
     def read_range(self, start: int, stop: int) -> CSRBatch:
         """Raw contiguous read of local rows ``[start, stop)`` — ONE extent.
@@ -270,6 +292,11 @@ class ShardedCSRStore:
     @property
     def avg_row_bytes(self) -> float:
         return float(np.mean([s.avg_row_bytes for s in self.shards]))
+
+    @property
+    def ell_width(self) -> int:
+        """Dataset-level ELL K over every shard (see :func:`_ell_width`)."""
+        return max(s.ell_width for s in self.shards)
 
     @property
     def obs_keys(self) -> list[str]:

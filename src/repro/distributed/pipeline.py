@@ -23,7 +23,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -79,23 +78,24 @@ def pipeline_apply(
             )
             return (nxt, outs), None
 
-        state0 = jnp.zeros(mb_shape, x_local.dtype)
-        outs0 = jnp.zeros((M,) + mb_shape, x_local.dtype)
+        # the carries differ per stage: type them as varying over the axis
+        state0 = jax.lax.pcast(jnp.zeros(mb_shape, x_local.dtype), axis, to="varying")
+        outs0 = jax.lax.pcast(
+            jnp.zeros((M,) + mb_shape, x_local.dtype), axis, to="varying"
+        )
         (state, outs), _ = jax.lax.scan(
             tick, (state0, outs0), jnp.arange(M + S - 1)
         )
         # every device returns an outs buffer; only the last stage's is real.
-        # psum with a mask keeps it SPMD-uniform.
+        # psum with a mask keeps it SPMD-uniform and makes the result
+        # replicated over the stage axis.
         mask = jnp.equal(sid, S - 1).astype(outs.dtype)
-        return jax.lax.psum(outs * mask, axis)[None]  # (1, M, mb, d)
+        return jax.lax.psum(outs * mask, axis)  # (M, mb, d)
 
     param_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    out = shard_map(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(param_specs, P()),
-        out_specs=P(axis),
-        check_rep=False,
+        out_specs=P(),
     )(stage_params, x)
-    # out: (S, M, mb, d) — identical (masked-psum) on every stage row; take 0
-    return out[0]

@@ -12,6 +12,7 @@ all-reduce), proving the distribution config scales past one ICI domain.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "HW"]
 
@@ -23,11 +24,12 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh():
-    """Degenerate 1×1 mesh over the real local device (smoke tests, examples)."""
-    n = jax.device_count()
-    if n >= 2:
-        return jax.make_mesh((n // (n // 2) if False else 1, n), ("data", "model"))
-    return jax.make_mesh((1, 1), ("data", "model"))
+    """1-D ``("data",)`` mesh over every local device: one data-parallel rank
+    per chip.  Axes are Auto, so a jitted step given batch-sharded inputs and
+    replicated parameters gets its gradient all-reduce from the compiler."""
+    return jax.make_mesh(
+        (jax.device_count(),), ("data",), axis_types=(AxisType.Auto,)
+    )
 
 
 class HW:

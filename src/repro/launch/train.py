@@ -27,6 +27,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config, smoke_config
 from repro.core import LoaderState
 from repro.data.tokens import generate_token_corpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.pipeline import DataPipeline, Pipeline
 from repro.train.optimizer import AdamWConfig, warmup_cosine
@@ -150,6 +151,7 @@ def main(argv=None) -> int:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--corpus", default="/tmp/repro_corpus")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family in ("vlm", "encdec"):

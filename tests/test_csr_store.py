@@ -86,6 +86,36 @@ def test_ell_roundtrip(shard):
     assert np.allclose(out, dense[rows])
 
 
+def test_to_ell_rejects_k_below_longest_row(shard):
+    """A narrow k_max used to clip rows and drop nonzeros without a word."""
+    store, dense = shard
+    b = store[np.arange(64)]
+    longest = int(np.diff(b.indptr).max())
+    with pytest.raises(ValueError, match="drop data"):
+        b.to_ell(k_max=longest - 1)
+    vals, cols = b.to_ell(k_max=longest + 5)  # wider than needed: padding only
+    assert vals.shape == (64, longest + 5)
+    assert (cols[:, longest:] == -1).all()
+
+
+def test_ell_width_is_dataset_level_and_lane_aligned(tmp_path):
+    """One K for the whole store: every batch's ELL has the same shape."""
+    rng = np.random.default_rng(3)
+    paths, longest = [], 0
+    for s, max_nnz in enumerate((12, 140)):
+        data, indices, indptr, _ = _random_csr(rng, 50, 300, max_nnz=max_nnz)
+        longest = max(longest, int(np.diff(indptr).max()))
+        p = str(tmp_path / f"s{s}")
+        write_csr_shard(p, data, indices, indptr, 300, {"plate": np.full(50, s)})
+        paths.append(p)
+    store = ShardedCSRStore(paths)
+    assert store.ell_width == -(-longest // 128) * 128 == 256
+    assert store.shards[0].ell_width == 128
+    shapes = {store[rows].to_ell(k_max=store.ell_width)[0].shape
+              for rows in (np.arange(8), np.arange(50, 58), np.arange(92, 100))}
+    assert shapes == {(8, 256)}
+
+
 def test_sharded_concat(tmp_path):
     rng = np.random.default_rng(2)
     denses, paths = [], []

@@ -59,10 +59,10 @@ def test_flash_attention_q_offset_decode_tile():
 
 # ------------------------------------------------------------------- ELL
 @pytest.mark.parametrize("R,K,G,br,bc", [
-    (16, 8, 64, 8, 64),
-    (33, 5, 100, 8, 32),     # ragged rows + ragged col tiles
-    (8, 16, 512, 4, 128),
-    (1, 1, 8, 8, 8),
+    (16, 8, 64, 128, 64),
+    (200, 5, 100, 128, 32),  # ragged row tiles + ragged col tiles
+    (8, 16, 512, 128, 128),
+    (1, 1, 8, 128, 8),
 ])
 def test_ell_to_dense_sweep(R, K, G, br, bc):
     vals = jnp.asarray(RNG.normal(0, 1, (R, K)), jnp.float32)
@@ -76,8 +76,7 @@ def test_ell_to_dense_sweep(R, K, G, br, bc):
 def test_ell_duplicate_columns_accumulate():
     vals = jnp.asarray([[1.0, 2.0, 3.0]], jnp.float32)
     cols = jnp.asarray([[4, 4, -1]], jnp.int32)
-    out = ell_to_dense(vals, cols, n_cols=8, block_rows=8, block_cols=8,
-                       interpret=True)
+    out = ell_to_dense(vals, cols, n_cols=8, block_cols=8, interpret=True)
     assert float(out[0, 4]) == 3.0
     assert float(jnp.abs(out).sum()) == 3.0
 
@@ -102,7 +101,7 @@ def test_ell_matches_csr_batch(tmp_path):
     b = CSRStore(p)[np.arange(n)]
     vals, cols = b.to_ell()
     out = ell_to_dense(jnp.asarray(vals), jnp.asarray(cols), n_cols=g,
-                       block_rows=8, block_cols=32, interpret=True)
+                       block_cols=32, interpret=True)
     np.testing.assert_allclose(np.asarray(out), b.to_dense(), atol=1e-6)
 
 
