@@ -25,6 +25,7 @@ from typing import Any, Callable, Iterator, Optional
 
 import numpy as np
 
+from ..data.iostats import span
 from .callbacks import Callbacks, MultiIndexable, default_batch_callback
 from .sampling import BlockShuffling, SamplingStrategy, epoch_rng
 
@@ -565,78 +566,82 @@ class ScDataset:
         fetch_idx = order[lo:hi]
         if len(fetch_idx) == 0:
             return []
-        cbs = self.callbacks
+        with span("scdataset.fetch", epoch=int(epoch), fetch=int(global_fetch_id),
+                  rows=len(fetch_idx)):
+            cbs = self.callbacks
 
-        if self.sort_fetch_indices:
-            sort_perm = np.argsort(fetch_idx, kind="stable")  # line 7
-            sorted_idx = fetch_idx[sort_perm]
-        else:
-            sorted_idx = fetch_idx
-
-        # Double buffering: issue the NEXT fetches' read plans (non-blocking)
-        # BEFORE blocking on this fetch's I/O, so background planner reads
-        # overlap this fetch's reads, assembly, and consumption.  Repeat
-        # issues are cheap no-ops (cached / in-flight blocks are skipped), so
-        # idempotent re-execution of a fetch stays safe.  ``readahead`` is
-        # consulted per fetch on purpose: under readahead="auto" the
-        # collection's controller moves the depth while we iterate.
-        ra = int(getattr(self.collection, "readahead", 0) or 0)
-        if ra > 0:
-            g = self._global_fetch_count()
-            if self._fetch_plan is not None:
-                # explicit plan (post-resize): the upcoming gids are the plan
-                # entries after THIS one, not a round-robin stride — guessing
-                # the stride would stage blocks this rank will never fetch
-                gids = [gid for gid, _ in self._fetch_plan]
-                try:
-                    pos = gids.index(global_fetch_id)
-                    upcoming = gids[pos + 1 : pos + 1 + ra]
-                except ValueError:
-                    upcoming = []
+            if self.sort_fetch_indices:
+                sort_perm = np.argsort(fetch_idx, kind="stable")  # line 7
+                sorted_idx = fetch_idx[sort_perm]
             else:
-                upcoming = [
-                    global_fetch_id + k * self.world_size
-                    for k in range(1, ra + 1)
-                ]
-            issued = 0
-            for nxt in upcoming:
-                if nxt >= g or not self._issue_prefetch(order, nxt):
-                    break
-                issued += 1
-            if self.cross_epoch_prefetch and issued < ra:
-                # Epoch tail: the in-epoch window ran out, so fill the rest
-                # from epoch e+1's FIRST fetches of this rank — the epoch
-                # boundary stops draining the pipeline.  Same rendezvous
-                # table, so epoch e+1's first fetch finds its blocks staged
-                # (or in flight) instead of cold.  Next epoch's order is a
-                # pure function of (seed, epoch+1) and lands in the 2-slot
-                # order cache this epoch's remaining fetches don't need.
-                order2 = self._epoch_order(epoch + 1)
-                for j in range(ra - issued):
-                    nxt2 = self.rank + j * self.world_size
-                    if nxt2 >= g or not self._issue_prefetch(order2, nxt2):
+                sorted_idx = fetch_idx
+
+            # Double buffering: issue the NEXT fetches' read plans (non-blocking)
+            # BEFORE blocking on this fetch's I/O, so background planner reads
+            # overlap this fetch's reads, assembly, and consumption.  Repeat
+            # issues are cheap no-ops (cached / in-flight blocks are skipped), so
+            # idempotent re-execution of a fetch stays safe.  ``readahead`` is
+            # consulted per fetch on purpose: under readahead="auto" the
+            # collection's controller moves the depth while we iterate.
+            ra = int(getattr(self.collection, "readahead", 0) or 0)
+            if ra > 0:
+                g = self._global_fetch_count()
+                if self._fetch_plan is not None:
+                    # explicit plan (post-resize): the upcoming gids are the plan
+                    # entries after THIS one, not a round-robin stride — guessing
+                    # the stride would stage blocks this rank will never fetch
+                    gids = [gid for gid, _ in self._fetch_plan]
+                    try:
+                        pos = gids.index(global_fetch_id)
+                        upcoming = gids[pos + 1 : pos + 1 + ra]
+                    except ValueError:
+                        upcoming = []
+                else:
+                    upcoming = [
+                        global_fetch_id + k * self.world_size
+                        for k in range(1, ra + 1)
+                    ]
+                issued = 0
+                for nxt in upcoming:
+                    if nxt >= g or not self._issue_prefetch(order, nxt):
                         break
+                    issued += 1
+                if self.cross_epoch_prefetch and issued < ra:
+                    # Epoch tail: the in-epoch window ran out, so fill the rest
+                    # from epoch e+1's FIRST fetches of this rank — the epoch
+                    # boundary stops draining the pipeline.  Same rendezvous
+                    # table, so epoch e+1's first fetch finds its blocks staged
+                    # (or in flight) instead of cold.  Next epoch's order is a
+                    # pure function of (seed, epoch+1) and lands in the 2-slot
+                    # order cache this epoch's remaining fetches don't need.
+                    order2 = self._epoch_order(epoch + 1)
+                    for j in range(ra - issued):
+                        nxt2 = self.rank + j * self.world_size
+                        if nxt2 >= g or not self._issue_prefetch(order2, nxt2):
+                            break
 
-        fetched = cbs.fetch_callback(self.collection, sorted_idx)  # line 8 — the ONLY disk I/O
-        fetched = cbs.fetch_transform(fetched)
+            fetched = cbs.fetch_callback(self.collection, sorted_idx)  # line 8 — the ONLY disk I/O
+            m = self.batch_size
+            n = len(sorted_idx)
+            nb = n // m if self.drop_last else (n + m - 1) // m
+            with span("scdataset.split", batches=nb):
+                fetched = cbs.fetch_transform(fetched)
 
-        rng = epoch_rng(self.seed, epoch, 0xF37C, global_fetch_id)
-        perm = rng.permutation(len(sorted_idx))  # line 9 — in-memory reshuffle
+                rng = epoch_rng(self.seed, epoch, 0xF37C, global_fetch_id)
+                perm = rng.permutation(len(sorted_idx))  # line 9 — in-memory reshuffle
 
-        batches = []
-        m = self.batch_size
-        nb = len(perm) // m if self.drop_last else (len(perm) + m - 1) // m
-        for j in range(nb):  # line 10
-            rows = perm[j * m : (j + 1) * m]
-            if len(rows) == 0:
-                continue
-            if self._div is not None:
-                # global row ids of this minibatch — telemetry only, the
-                # delivered stream is untouched (see DiversityMonitor)
-                self._div.observe(sorted_idx[rows])
-            batch = cbs.batch_callback(fetched, rows)
-            batches.append(cbs.batch_transform(batch))
-        return batches
+                batches = []
+                for j in range(nb):  # line 10
+                    rows = perm[j * m : (j + 1) * m]
+                    if len(rows) == 0:
+                        continue
+                    if self._div is not None:
+                        # global row ids of this minibatch — telemetry only, the
+                        # delivered stream is untouched (see DiversityMonitor)
+                        self._div.observe(sorted_idx[rows])
+                    batch = cbs.batch_callback(fetched, rows)
+                    batches.append(cbs.batch_transform(batch))
+            return batches
 
     # -------------------------------------------------------------- iterate
     def __iter__(self) -> Iterator:

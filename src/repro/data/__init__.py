@@ -62,7 +62,7 @@ from .faults import (
     TransientStorageError,
 )
 from .h5ad import H5adAdapter, H5adStore, ShardedH5adAdapter
-from .iostats import CLOUD_OBJECT, NVME_SSD, SATA_SSD, IOStats, PendingIO, StorageModel
+from .iostats import CLOUD_OBJECT, NVME_SSD, SATA_SSD, IOStats, PendingIO, StorageModel, span
 from .readplan import (
     BlockCache,
     SegmentedBlockCache,
@@ -107,6 +107,7 @@ __all__ = [
     "IOStats",
     "PendingIO",
     "StorageModel",
+    "span",
     "SATA_SSD",
     "NVME_SSD",
     "CLOUD_OBJECT",

@@ -37,7 +37,7 @@ import numpy as np
 
 from .chunked_store import ChunkedStore
 from .csr_store import CSRBatch, CSRStore, ShardedCSRStore, _concat_batches
-from .iostats import IOStats
+from .iostats import IOStats, span
 from .readplan import (
     BlockCache,
     SegmentedBlockCache,
@@ -708,7 +708,8 @@ class PlannedCollection:
         sleeps inflate it, which conservatively widens the hedge deadline
         while storage is misbehaving."""
         t0 = time.perf_counter()
-        piece = self._resilient_read(lo, hi)
+        with span("scdataset.read", start=int(lo), stop=int(hi)):
+            piece = self._resilient_read(lo, hi)
         nb = piece_nbytes(piece)
         self.iostats.sleep_for(runs=1, bytes_read=nb)
         dt = time.perf_counter() - t0
@@ -942,8 +943,12 @@ class PlannedCollection:
                     )
 
     def fetch(self, rows) -> Any:
-        t0 = time.perf_counter()
         rows = np.asarray(rows, dtype=np.int64)
+        with span("scdataset.plan", rows=int(rows.size)):
+            return self._fetch(rows)
+
+    def _fetch(self, rows: np.ndarray) -> Any:
+        t0 = time.perf_counter()
         if rows.ndim == 0:
             rows = rows[None]
         if len(rows) == 0:
@@ -1169,14 +1174,15 @@ class PlannedCollection:
                     self.cache.discard(b)
 
         # ---- fill the remaining parts, restore caller order --------------
-        for gi, (a, z, bb) in enumerate(groups):
-            if parts[gi] is None:
-                parts[gi] = self.adapter.take(local[bb], srows[a:z] - bb * B)
-        merged = parts[0] if len(parts) == 1 else self.adapter.concat(parts)
-        inv = np.empty(len(rows), dtype=np.int64)
-        inv[order] = np.arange(len(rows))
-        if not np.array_equal(inv, np.arange(len(rows))):
-            merged = self.adapter.take(merged, inv)
+        with span("scdataset.assemble"):
+            for gi, (a, z, bb) in enumerate(groups):
+                if parts[gi] is None:
+                    parts[gi] = self.adapter.take(local[bb], srows[a:z] - bb * B)
+            merged = parts[0] if len(parts) == 1 else self.adapter.concat(parts)
+            inv = np.empty(len(rows), dtype=np.int64)
+            inv[order] = np.arange(len(rows))
+            if not np.array_equal(inv, np.arange(len(rows))):
+                merged = self.adapter.take(merged, inv)
 
         # ---- cross-rank attribution (elastic fabric) ---------------------
         # Blocks this fetch obtained WITHOUT reading (cache hits + staged +

@@ -28,7 +28,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from .iostats import IOStats
+from .iostats import IOStats, span
 from .readplan import coalesce_rows
 
 __all__ = ["CSRBatch", "CSRStore", "ShardedCSRStore", "write_csr_shard"]
@@ -76,12 +76,13 @@ class CSRBatch:
         """Dense (rows, n_var).  Assumes canonical CSR (unique columns per
         row, as AnnData guarantees) — duplicate columns would overwrite, not
         accumulate; ``to_ell`` + the Pallas kernel accumulate."""
-        out = np.zeros((len(self), self.n_var), dtype=np.float32)
-        rows = np.repeat(
-            np.arange(len(self)), np.diff(self.indptr).astype(np.int64)
-        )
-        out[rows, self.indices.astype(np.int64)] = self.data
-        return out
+        with span("scdataset.to_dense", rows=len(self)):
+            out = np.zeros((len(self), self.n_var), dtype=np.float32)
+            rows = np.repeat(
+                np.arange(len(self)), np.diff(self.indptr).astype(np.int64)
+            )
+            out[rows, self.indices.astype(np.int64)] = self.data
+            return out
 
     def to_ell(self, k_max: Optional[int] = None) -> tuple[np.ndarray, np.ndarray]:
         """Pad to ELL format (rows, K): (values, cols) with col=-1 padding.

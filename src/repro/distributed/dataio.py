@@ -15,36 +15,10 @@ from typing import Any, Mapping, Optional
 import jax
 import numpy as np
 
+from ..data.iostats import span
 from .sharding import Rules, sharding_for_axes
 
-__all__ = ["put_batch", "batch_axes_for", "device_prefetch"]
-
-
-def device_prefetch(iterator, size: int = 2):
-    """Double-buffered host→device pipeline.
-
-    Keeps ``size`` batches in flight: while the device executes step t, the
-    host stages batch t+1's transfer (jax dispatch is async, so device_put
-    overlaps with compute).  The paper's host-side prefetch pool feeds this;
-    together they overlap disk → host RAM → HBM with the training step.
-    """
-    import collections
-    import itertools
-
-    queue = collections.deque()
-    it = iter(iterator)
-    try:
-        for _ in range(size):
-            queue.append(next(it))
-    except StopIteration:
-        pass
-    while queue:
-        out = queue.popleft()
-        try:
-            queue.append(next(it))
-        except StopIteration:
-            pass
-        yield out
+__all__ = ["put_batch", "batch_axes_for"]
 
 
 def batch_axes_for(batch: Mapping[str, Any]) -> dict:
@@ -70,13 +44,14 @@ def put_batch(
     axes: Optional[Mapping[str, tuple]] = None,
 ) -> dict:
     """device_put every leaf with its resolved NamedSharding."""
-    axes = axes or batch_axes_for(batch)
-    out = {}
-    for k, v in batch.items():
-        v = np.asarray(v)
-        sh = sharding_for_axes(axes[k], rules, mesh, v.shape)
-        if jax.process_count() > 1:  # pragma: no cover (multi-host path)
-            out[k] = jax.make_array_from_process_local_data(sh, v)
-        else:
-            out[k] = jax.device_put(v, sh)
-    return out
+    with span("scdataset.put_batch"):
+        axes = axes or batch_axes_for(batch)
+        out = {}
+        for k, v in batch.items():
+            v = np.asarray(v)
+            sh = sharding_for_axes(axes[k], rules, mesh, v.shape)
+            if jax.process_count() > 1:  # pragma: no cover (multi-host path)
+                out[k] = jax.make_array_from_process_local_data(sh, v)
+            else:
+                out[k] = jax.device_put(v, sh)
+        return out
