@@ -36,7 +36,15 @@ from typing import Any, Callable, Optional, Protocol, Sequence, runtime_checkabl
 import numpy as np
 
 from .chunked_store import ChunkedStore
-from .csr_store import CSRBatch, CSRStore, ShardedCSRStore, _concat_batches
+from .csr_store import (
+    BufferPool,
+    CSRBatch,
+    CSRStore,
+    ShardedCSRStore,
+    _concat_batches,
+    gather_rows,
+    gathered_nbytes,
+)
 from .iostats import IOStats, span
 from .readplan import (
     BlockCache,
@@ -130,6 +138,25 @@ class StorageAdapter:
         """Concatenate batches in order."""
         raise NotImplementedError
 
+    def gather(self, sources: Sequence[tuple[Any, np.ndarray]]) -> Any:
+        """One batch of ``take(piece, rows)`` for each ``(piece, rows)`` of
+        ``sources``, in order: how the planner assembles a fetch from the
+        read extents and cached blocks that hold its rows.  Default: that,
+        then :meth:`concat`.  Batch types that can copy whole runs of rows
+        at once override it to build the batch in one copy."""
+        parts = [self.take(piece, rows) for piece, rows in sources]
+        return parts[0] if len(parts) == 1 else self.concat(parts)
+
+    def gather_nbytes(self, ranges: Sequence[tuple[Any, int, int]]) -> int:
+        """In-memory bytes of the :meth:`gather` of rows ``[lo, hi)`` of
+        each ``(piece, lo, hi)``: what the block cache would be charged for
+        it.  Default: build it and measure.  Adapters that can read the
+        size off the pieces override it, so the planner sizes a block it
+        will not keep without copying it."""
+        return piece_nbytes(
+            self.gather([(piece, np.arange(lo, hi)) for piece, lo, hi in ranges])
+        )
+
     def nbytes_of(self, rows: np.ndarray) -> int:
         """Estimated payload bytes of ``rows`` without reading them."""
         raise NotImplementedError
@@ -158,6 +185,12 @@ class StorageAdapter:
         planner counts itself.
         """
 
+    def end_fetch(self) -> None:
+        """Called by :class:`PlannedCollection` when a demand fetch has
+        returned its batch.  Default: nothing.  Adapters that keep buffers
+        across fetches give back here those the fetch did not use.
+        Wrappers must delegate to their inner adapter."""
+
     def close(self) -> None:
         """Release OS resources (file handles).  Default: nothing to do
         (mmap-backed stores release on GC).  Reached through
@@ -171,18 +204,28 @@ class CSRAdapter(StorageAdapter):
 
     def __init__(self, store: CSRStore):
         self.store = store
+        self.pool = BufferPool()  # read extents and gathered batches
 
     def __len__(self) -> int:
         return len(self.store)
 
     def read_range(self, start: int, stop: int) -> CSRBatch:
-        return self.store.read_range(start, stop)
+        return self.store.read_range(start, stop, self.pool)
 
     def take(self, piece: CSRBatch, rows: np.ndarray) -> CSRBatch:
         return piece[rows]
 
     def concat(self, pieces: Sequence[CSRBatch]) -> CSRBatch:
         return _concat_batches(list(pieces), self.store.n_var)
+
+    def gather(self, sources: Sequence[tuple[CSRBatch, np.ndarray]]) -> CSRBatch:
+        return gather_rows(sources, self.store.n_var, self.pool)
+
+    def gather_nbytes(self, ranges: Sequence[tuple[CSRBatch, int, int]]) -> int:
+        return gathered_nbytes(ranges)
+
+    def end_fetch(self) -> None:
+        self.pool.trim()
 
     def nbytes_of(self, rows: np.ndarray) -> int:
         rows = np.asarray(rows, dtype=np.int64)
@@ -231,6 +274,7 @@ class CSRCompositeAdapter(StorageAdapter):
         sizes = np.array([len(s) for s in self.stores], dtype=np.int64)
         self.offsets = np.concatenate(([0], np.cumsum(sizes)))
         self.n_obs = int(self.offsets[-1])
+        self.pool = BufferPool()  # read extents and gathered batches
 
     def __len__(self) -> int:
         return self.n_obs
@@ -241,13 +285,22 @@ class CSRCompositeAdapter(StorageAdapter):
     def read_range(self, start: int, stop: int) -> CSRBatch:
         sid = int(np.searchsorted(self.offsets, start, side="right") - 1)
         off = int(self.offsets[sid])
-        return self.stores[sid].read_range(start - off, stop - off)
+        return self.stores[sid].read_range(start - off, stop - off, self.pool)
 
     def take(self, piece: CSRBatch, rows: np.ndarray) -> CSRBatch:
         return piece[rows]
 
     def concat(self, pieces: Sequence[CSRBatch]) -> CSRBatch:
         return _concat_batches(list(pieces), self.n_var)
+
+    def gather(self, sources: Sequence[tuple[CSRBatch, np.ndarray]]) -> CSRBatch:
+        return gather_rows(sources, self.n_var, self.pool)
+
+    def gather_nbytes(self, ranges: Sequence[tuple[CSRBatch, int, int]]) -> int:
+        return gathered_nbytes(ranges)
+
+    def end_fetch(self) -> None:
+        self.pool.trim()
 
     def nbytes_of(self, rows: np.ndarray) -> int:
         rows = np.asarray(rows, dtype=np.int64)
@@ -315,6 +368,9 @@ class ChunkedAdapter(StorageAdapter):
     def concat(self, pieces: Sequence[np.ndarray]) -> np.ndarray:
         return np.concatenate(list(pieces))
 
+    def gather_nbytes(self, ranges: Sequence[tuple[np.ndarray, int, int]]) -> int:
+        return int(sum(piece[lo:hi].nbytes for piece, lo, hi in ranges))
+
     def nbytes_of(self, rows: np.ndarray) -> int:
         return int(len(np.asarray(rows)) * self.store.d * 4)
 
@@ -359,6 +415,9 @@ class TokenAdapter(StorageAdapter):
         keys = pieces[0].keys()
         return {k: np.concatenate([p[k] for p in pieces]) for k in keys}
 
+    def gather_nbytes(self, ranges: Sequence[tuple[dict, int, int]]) -> int:
+        return int(sum(v[lo:hi].nbytes for piece, lo, hi in ranges for v in piece.values()))
+
     def nbytes_of(self, rows: np.ndarray) -> int:
         return int(len(np.asarray(rows)) * self.store.avg_row_bytes)
 
@@ -384,7 +443,10 @@ class PlannedCollection:
     blocks from the LRU byte-budgeted :class:`~repro.data.readplan.BlockCache`
     and reads the rest as maximal contiguous runs — merged across shard
     boundaries in planning, split back at physical boundaries and at
-    ``max_extent_rows`` for execution.  One IOStats record per fetch counts
+    ``max_extent_rows`` for execution.  The batch is gathered in one
+    :meth:`StorageAdapter.gather` from the read extents and the blocks
+    served; a missed block is cut out of its extent only for the cache or
+    a waiting fetch that will read it.  One IOStats record per fetch counts
     runs (physical reads actually issued), bytes, rows, and block cache
     hits/misses — identically for every backend.
 
@@ -393,9 +455,8 @@ class PlannedCollection:
 
     - ``io_workers > 1`` — a fetch's miss extents execute concurrently on a
       shared bounded thread pool (mmap/numpy/decompress reads release the
-      GIL); cache-hit blocks are assembled while misses are in flight, and
-      pieces are gathered in plan order, so delivery stays bit-identical to
-      the synchronous path.
+      GIL); pieces are gathered in plan order, so delivery stays
+      bit-identical to the synchronous path.
     - ``readahead > 0`` — :meth:`prefetch` issues a *future* fetch's read
       plan in the background (``ScDataset`` calls it with the next fetches'
       indices before blocking on the current fetch).  In-flight blocks are
@@ -860,14 +921,8 @@ class PlannedCollection:
         try:
             spans = self._spans_for_blocks(np.asarray([b]))
             results = [self._read_one(lo, hi) for lo, hi in spans]
-            pieces = [p for p, _ in results]
             nb = sum(x for _, x in results)
-            pending: dict[int, list] = {b: []}
-            self._slice_spans_into_blocks(
-                self.adapter, self.block_rows, spans, pieces, pending
-            )
-            plist = pending[b]
-            val = plist[0] if len(plist) == 1 else self.adapter.concat(plist)
+            val = self._cut(b, spans, [p for p, _ in results])
             with self._fl:
                 streaming = self._stream.streaming
             outcome = self._cache_put(b, val, last_block=b, streaming=streaming)
@@ -919,33 +974,128 @@ class PlannedCollection:
         self.cache.put(block, val, nb)
         return "stored"
 
-    @staticmethod
-    def _slice_spans_into_blocks(
-        adapter: StorageAdapter,
-        B: int,
-        spans: Sequence[tuple[int, int]],
+    def _block_parts(
+        self, bb: int, spans: np.ndarray, pieces: Sequence[Any]
+    ) -> tuple[Any, list]:
+        """Where missed block ``bb``'s rows lie among a fetch's read extents:
+        ``(extent, [])`` where the block is a whole extent, else ``(None,
+        [(piece, first, stop), ...])`` per extent holding some of it, rows
+        relative to the extent (a block splits where a shard edge or
+        ``max_extent_rows`` cuts it)."""
+        B = self.block_rows
+        blo, bhi = bb * B, min((bb + 1) * B, len(self.adapter))
+        k = int(np.searchsorted(spans[:, 0], blo, side="right")) - 1
+        parts = []
+        while k < len(spans) and spans[k, 0] < bhi:
+            lo, hi = int(spans[k, 0]), int(spans[k, 1])
+            if (lo, hi) == (blo, bhi):
+                return pieces[k], []
+            parts.append((pieces[k], max(lo, blo) - lo, min(hi, bhi) - lo))
+            k += 1
+        return None, parts
+
+    def _cut(self, bb: int, spans: np.ndarray, pieces: Sequence[Any]) -> Any:
+        """Missed block ``bb`` as a value of its own (a copy, so a cached
+        block never pins the extent it came from), or the extent itself
+        where the two coincide."""
+        whole, parts = self._block_parts(bb, spans, pieces)
+        if whole is not None:
+            return whole
+        return self.adapter.gather([(p, np.arange(a, z)) for p, a, z in parts])
+
+    def _publish_misses(
+        self,
+        missing: list[int],
+        spans: np.ndarray,
         pieces: Sequence[Any],
-        pending: dict[int, list],
-    ) -> None:
-        """Cut span pieces at cache-block edges into ``pending`` (in span
-        order — deterministic regardless of read completion order)."""
-        for (lo, hi), piece in zip(spans, pieces):
-            b0, b1 = lo // B, (hi - 1) // B
-            for bb in range(b0, b1 + 1):
-                if bb not in pending:
-                    continue
-                blo, bhi = max(lo, bb * B), min(hi, (bb + 1) * B)
-                if blo == lo and bhi == hi:
-                    pending[bb].append(piece)
-                else:
-                    pending[bb].append(
-                        adapter.take(piece, np.arange(blo - lo, bhi - lo))
-                    )
+        claimed: dict[int, Future],
+        *,
+        last_block: int,
+        streaming: bool,
+    ) -> tuple[int, int, int]:
+        """Insert a fetch's missed blocks into the cache, in block order, as
+        the admission policy says, and hand claimed blocks to their futures.
+
+        A block is cut out of its extent only if someone will read it: a
+        claimant (async mode), or the cache, which under plain LRU still
+        holds at the end only the newest blocks that fit its budget
+        (:meth:`BlockCache.survivors`).  The others are inserted as None
+        and evicted within the same :meth:`BlockCache.put_many`, so the
+        cache's contents, order and counters come out as if every block had
+        been cut.  Where the outcome is known only by trying (the TinyLFU
+        duel), every block is cut.  Returns ``(blocks cut, bypassed,
+        rejected)``.
+        """
+        values: dict[int, Any] = {}
+        bypassed = rejected = 0
+        if self._sketch is not None and not streaming:
+            for bb in missing:
+                values[bb] = self._cut(bb, spans, pieces)
+                outcome = self._cache_put(bb, values[bb], last_block=last_block,
+                                          streaming=streaming)
+                bypassed += outcome == "bypassed"
+                rejected += outcome == "rejected"
+        else:
+            kept = [bb for bb in missing
+                    if self.admission != "never" and (not streaming or bb == last_block)]
+            bypassed = len(missing) - len(kept)
+            if bypassed:
+                self.cache.bypass(bypassed)
+            if self.cache.max_bytes <= 0:
+                kept = []  # no cache: an insertion would do nothing at all
+            sizes = []
+            for bb in kept:
+                whole, parts = self._block_parts(bb, spans, pieces)
+                sizes.append(piece_nbytes(whole) if whole is not None
+                             else self.adapter.gather_nbytes(parts))
+            for bb, keep in zip(kept, self.cache.survivors(sizes)):
+                if keep and bb not in values:
+                    values[bb] = self._cut(bb, spans, pieces)
+            for bb in claimed:
+                if bb not in values:
+                    values[bb] = self._cut(bb, spans, pieces)
+            self.cache.put_many(
+                [(bb, values.get(bb), nb) for bb, nb in zip(kept, sizes)]
+            )
+        for bb, f in claimed.items():
+            f.set_result(values[bb])
+        return len(values), bypassed, rejected
+
+    def _sources(
+        self,
+        srows: np.ndarray,
+        local: dict[int, Any],
+        spans: np.ndarray,
+        pieces: Sequence[Any],
+    ) -> list[tuple[Any, np.ndarray]]:
+        """The ``(piece, rows)`` runs of sorted rows ``srows`` for
+        :meth:`StorageAdapter.gather`: a row of a block this fetch holds a
+        value of (cache hit, rendezvous) comes from that block, any other
+        from the read extent that holds it."""
+        B = self.block_rows
+        sblocks = srows // B
+        key = sblocks
+        if len(spans):
+            held = np.isin(sblocks, np.fromiter(local, dtype=np.int64, count=len(local)))
+            extent = np.searchsorted(spans[:, 0], srows, side="right") - 1
+            key = np.where(held, sblocks, -1 - extent)
+        edges = np.flatnonzero(np.diff(key) != 0) + 1
+        out = []
+        for a, z in zip([0, *edges.tolist()], [*edges.tolist(), len(srows)]):
+            k = int(key[a])
+            if k >= 0:
+                out.append((local[k], srows[a:z] - k * B))
+            else:
+                out.append((pieces[-1 - k], srows[a:z] - int(spans[-1 - k, 0])))
+        return out
 
     def fetch(self, rows) -> Any:
         rows = np.asarray(rows, dtype=np.int64)
         with span("scdataset.plan", rows=int(rows.size)):
-            return self._fetch(rows)
+            try:
+                return self._fetch(rows)
+            finally:
+                self.adapter.end_fetch()
 
     def _fetch(self, rows: np.ndarray) -> Any:
         t0 = time.perf_counter()
@@ -1051,7 +1201,7 @@ class PlannedCollection:
 
         # ---- plan + issue the physical reads -----------------------------
         bytes_read = 0
-        spans: list[tuple[int, int]] = []
+        spans = np.empty((0, 2), dtype=np.int64)
         read_futs = None
         pieces: list[Any] = []
         pool: Optional[ThreadPoolExecutor] = None
@@ -1071,22 +1221,8 @@ class PlannedCollection:
                     for lo, hi in spans
                 ]
 
-        # ---- assembly prep: overlaps with in-flight miss reads -----------
-        order = np.argsort(rows, kind="stable")
-        srows = rows[order]
-        sblocks = srows // B
-        edges = np.flatnonzero(np.diff(sblocks) != 0) + 1
-        starts = np.concatenate(([0], edges))
-        stops = np.concatenate((edges, [len(srows)]))
-        groups = [
-            (a, z, int(sblocks[a])) for a, z in zip(starts.tolist(), stops.tolist())
-        ]
-        parts: list = [None] * len(groups)
-        for gi, (a, z, bb) in enumerate(groups):
-            if bb in local:  # cache hits assemble while misses are read
-                parts[gi] = self.adapter.take(local[bb], srows[a:z] - bb * B)
-
-        # ---- gather own reads (plan order), build + publish blocks -------
+        # ---- gather own reads (plan order), publish the blocks kept -----
+        n_cut = 0
         if missing:
             try:
                 if read_futs is not None:
@@ -1098,20 +1234,10 @@ class PlannedCollection:
                     results = [self._read_one(lo, hi) for lo, hi in spans]
                 pieces = [p for p, _ in results]
                 bytes_read = sum(nb for _, nb in results)
-                pending: dict[int, list] = {b: [] for b in missing}
-                self._slice_spans_into_blocks(self.adapter, B, spans, pieces, pending)
-                for bb, plist in pending.items():
-                    val = plist[0] if len(plist) == 1 else self.adapter.concat(plist)
-                    local[bb] = val
-                    outcome = self._cache_put(bb, val, last_block=last_block,
-                                              streaming=streaming)
-                    if outcome == "bypassed":
-                        adm_bypassed += 1
-                    elif outcome == "rejected":
-                        adm_rejected += 1
-                    f = claimed.get(bb)
-                    if f is not None:
-                        f.set_result(val)
+                n_cut, adm_bypassed, adm_rejected = self._publish_misses(
+                    missing, spans, pieces, claimed,
+                    last_block=last_block, streaming=streaming,
+                )
                 if claimed:
                     with self._fl:
                         for bb, f in claimed.items():
@@ -1152,6 +1278,7 @@ class PlannedCollection:
                     hits += 1  # another recoverer delivered it to us
                 else:
                     missing.append(b)  # a miss this fetch served itself
+                    n_cut += 1
                     reissue_runs += runs2
                     bytes_read += nb2
                     if outcome == "bypassed":
@@ -1173,12 +1300,12 @@ class PlannedCollection:
                 if self.admission == "never" or b != last_block:
                     self.cache.discard(b)
 
-        # ---- fill the remaining parts, restore caller order --------------
+        # ---- one gather from extents and held blocks, caller order -------
         with span("scdataset.assemble"):
-            for gi, (a, z, bb) in enumerate(groups):
-                if parts[gi] is None:
-                    parts[gi] = self.adapter.take(local[bb], srows[a:z] - bb * B)
-            merged = parts[0] if len(parts) == 1 else self.adapter.concat(parts)
+            order = np.argsort(rows, kind="stable")
+            merged = self.adapter.gather(
+                self._sources(rows[order], local, spans, pieces)
+            )
             inv = np.empty(len(rows), dtype=np.int64)
             inv[order] = np.arange(len(rows))
             if not np.array_equal(inv, np.arange(len(rows))):
@@ -1216,6 +1343,7 @@ class PlannedCollection:
             adm_bypassed=adm_bypassed,
             adm_rejected=adm_rejected,
             shared_rank_hits=shared,
+            blocks_cut=n_cut,
             slept=True,
         )
         return merged
@@ -1293,23 +1421,15 @@ class PlannedCollection:
                 return sum(len(g) for g in groups[:gi])
         return len(todo)
 
-    def _prefetch_group(
-        self, spans: list[tuple[int, int]], futs: dict[int, Future]
-    ) -> None:
+    def _prefetch_group(self, spans: np.ndarray, futs: dict[int, Future]) -> None:
         """Executor task: read one contiguous block group, publish its blocks
         (cache first, then future, then rendezvous deregistration — waiters
         observing no inflight entry are guaranteed a cache peek succeeds)."""
-        B = self.block_rows
         try:
             results = [self._read_one(lo, hi) for lo, hi in spans]
             pieces = [p for p, _ in results]
             bytes_read = sum(nb for _, nb in results)
-            pending: dict[int, list] = {b: [] for b in futs}
-            self._slice_spans_into_blocks(self.adapter, B, spans, pieces, pending)
-            vals = {
-                bb: plist[0] if len(plist) == 1 else self.adapter.concat(plist)
-                for bb, plist in pending.items()
-            }
+            vals = {bb: self._cut(bb, spans, pieces) for bb in futs}
             # stage through the cache as the hand-off channel, MARKED: the
             # consuming fetch counts the first touch as `prefetched` (not a
             # hit) and, under a bypassing admission policy, drops the entry

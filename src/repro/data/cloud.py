@@ -155,6 +155,15 @@ class CloudAdapter(StorageAdapter):
     def concat(self, pieces: Sequence[Any]) -> Any:
         return self.inner.concat(pieces)
 
+    def gather(self, sources: Sequence[tuple[Any, np.ndarray]]) -> Any:
+        return self.inner.gather(sources)
+
+    def gather_nbytes(self, ranges: Sequence[tuple[Any, int, int]]) -> int:
+        return self.inner.gather_nbytes(ranges)
+
+    def end_fetch(self) -> None:
+        self.inner.end_fetch()
+
     def nbytes_of(self, rows: np.ndarray) -> int:
         return self.inner.nbytes_of(rows)
 

@@ -23,6 +23,8 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import sys
+import threading
 import time
 from typing import Optional, Sequence
 
@@ -31,7 +33,117 @@ import numpy as np
 from .iostats import IOStats, span
 from .readplan import coalesce_rows
 
-__all__ = ["CSRBatch", "CSRStore", "ShardedCSRStore", "write_csr_shard"]
+__all__ = [
+    "BufferPool",
+    "CSRBatch",
+    "CSRStore",
+    "ShardedCSRStore",
+    "gather_rows",
+    "gathered_nbytes",
+    "write_csr_shard",
+]
+
+
+# Reuse by reference count needs a count that only the GIL keeps exact.
+_REFCOUNTS_SHOW_USE = sys.implementation.name == "cpython" and getattr(
+    sys, "_is_gil_enabled", lambda: True
+)()
+
+
+class BufferPool:
+    """Large host arrays, handed out again once nothing refers to them.
+
+    A fresh page costs a fault on first touch, and glibc returns an array
+    above its mmap threshold (32 MiB at most) to the OS as soon as it is
+    freed, so a fetch that reads its extents and gathers its batch into
+    fresh arrays pays those faults again every time: on the TPU v5e host
+    (gVisor) filling 256 MiB takes ~290 ms fresh against ~124 ms warm.  The
+    pool keeps such buffers.  A buffer is free again when CPython's
+    reference count shows that no array is a view of it (every view of a
+    view refers to the buffer itself), so callers never give anything
+    back.  That assumes CPython with the GIL, and that whatever reads a
+    batch's memory holds a Python reference to it, as NumPy views and the
+    buffer protocol do; on any other interpreter the pool hands out fresh
+    arrays.
+
+    Bounds: the free buffers never hold more bytes than were ever in use
+    at once, and :meth:`trim`, which ends a window, cuts them to the most
+    in use at once during that window or the one before; the least
+    recently handed out go first.  The planner trims at the end of every
+    fetch (:meth:`~repro.data.backend.StorageAdapter.end_fetch`), so a
+    collection keeps the peak of its last two fetches, not the largest it
+    ever made.
+    """
+
+    MIN_BYTES = 1 << 20  # below this malloc's heap serves arrays warm already
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        # a buffer only this list refers to: what _refcounts reads for a free one
+        self._bufs: list[np.ndarray] = [np.empty(0, np.uint8)]  # guarded-by: _lock
+        (self._idle,) = self._refcounts()
+        self._bufs = []  # least recently handed out first
+        self._peak = 0  # guarded-by: _lock — most bytes in use at once this window
+        self._last_peak = 0  # guarded-by: _lock — the same, the window before
+        self._max_peak = 0  # guarded-by: _lock — the same, ever
+
+    def _refcounts(self) -> list[int]:
+        return [sys.getrefcount(b) for b in self._bufs]  # unlocked-ok: callers hold _lock or own the pool
+
+    @staticmethod
+    def _bounded(bufs: list, free: list[bool], bound: int) -> list:
+        """``bufs`` less the least recently handed out free buffers beyond
+        ``bound`` bytes."""
+        spare = sum(b.nbytes for b, f in zip(bufs, free) if f)
+        keep = []
+        for b, f in zip(bufs, free):
+            if f and spare > bound:
+                spare -= b.nbytes
+            else:
+                keep.append(b)
+        return keep
+
+    def empty(self, n: int, dtype) -> np.ndarray:
+        """An uninitialised ``(n,)`` array of ``dtype``."""
+        dtype = np.dtype(dtype)
+        nbytes = int(n) * dtype.itemsize
+        if nbytes < self.MIN_BYTES or not _REFCOUNTS_SHOW_USE:
+            return np.empty(n, dtype)
+        with self._lock:
+            free = [r <= self._idle for r in self._refcounts()]
+            fits = [(b.nbytes, i) for i, (b, f) in enumerate(zip(self._bufs, free))
+                    if f and b.nbytes >= nbytes]
+            if fits:
+                i = min(fits)[1]
+                buf = self._bufs.pop(i)
+                free.pop(i)
+            else:
+                # capacity rounded up to an eighth of the next power of two,
+                # so a slightly larger extent next time still fits
+                step = 1 << max(0, (nbytes - 1).bit_length() - 3)
+                buf = np.empty(-(-nbytes // step) * step, np.uint8)
+            self._bufs.append(buf)
+            free.append(False)
+            in_use = sum(b.nbytes for b, f in zip(self._bufs, free) if not f)
+            self._peak = max(self._peak, in_use)
+            self._max_peak = max(self._max_peak, in_use)
+            self._bufs = self._bounded(self._bufs, free, self._max_peak)
+        return buf[:nbytes].view(dtype)
+
+    def trim(self) -> None:
+        """End a window: keep free buffers of at most the most bytes in use
+        at once during it or the window before."""
+        with self._lock:
+            free = [r <= self._idle for r in self._refcounts()]
+            in_use = sum(b.nbytes for b, f in zip(self._bufs, free) if not f)
+            self._bufs = self._bounded(self._bufs, free, max(self._peak, self._last_peak))
+            self._last_peak, self._peak = self._peak, in_use
+
+    def copy(self, arr: np.ndarray) -> np.ndarray:
+        """``np.array(arr)`` (a memmap slice read into RAM) in a pool buffer."""
+        out = self.empty(len(arr), arr.dtype)
+        np.copyto(out, arr)
+        return out
 
 
 @dataclasses.dataclass
@@ -190,8 +302,9 @@ class CSRStore:
         """Dataset-level ELL K (see :func:`_ell_width`)."""
         return _ell_width(np.diff(self._indptr))
 
-    def read_range(self, start: int, stop: int) -> CSRBatch:
-        """Raw contiguous read of local rows ``[start, stop)`` — ONE extent.
+    def read_range(self, start: int, stop: int, pool: BufferPool) -> CSRBatch:
+        """Raw contiguous read of local rows ``[start, stop)`` — ONE extent,
+        into ``pool``'s buffers.
 
         No IOStats recording: this is the physical-read primitive the shared
         read planner (:mod:`repro.data.readplan`) executes; the planner does
@@ -199,12 +312,12 @@ class CSRStore:
         across backends.
         """
         lo, hi = int(self._indptr[start]), int(self._indptr[stop])
-        # np.array (not asarray): a memmap slice is a no-copy view, and the
+        # a copy, not a view: a memmap slice is a no-copy view, and the
         # planner CACHES what we return — a cached view would still fault
         # pages from disk on "hits" and occupy no budgetable RAM.
         return CSRBatch(
-            data=np.array(self._data[lo:hi]),
-            indices=np.array(self._indices[lo:hi]),
+            data=pool.copy(self._data[lo:hi]),
+            indices=pool.copy(self._indices[lo:hi]),
             indptr=np.asarray(self._indptr[start : stop + 1], dtype=np.int64) - lo,
             n_var=self.n_var,
             obs={k: v[start:stop] for k, v in self._obs.items()},
@@ -325,6 +438,62 @@ class ShardedCSRStore:
         merged = _concat_batches(got, self.n_var)
         # restore original order
         return merged[back_perm]
+
+
+def gather_rows(
+    sources: Sequence[tuple[CSRBatch, np.ndarray]], n_var: int, pool: BufferPool
+) -> CSRBatch:
+    """``_concat_batches([piece[rows] for piece, rows in sources])``, in one copy.
+
+    Each run of consecutive rows of a source (``r, r+1, ...`` in that
+    order) is one slice of its ``data`` and ``indices``; the slices are
+    copied once, by ``np.concatenate``, into arrays allocated once from
+    ``pool``, and ``indptr`` comes from the rows' lengths.  Rows may repeat and come in any order: a repeat or a step
+    back starts a new run.
+    """
+    first = sources[0][0]
+    data, indices = [first.data[:0]], [first.indices[:0]]
+    lens = [np.empty(0, dtype=np.int64)]
+    obs = {k: [v[:0]] for k, v in first.obs.items()}
+    for piece, rows in sources:
+        rows = np.asarray(rows, dtype=np.int64)
+        if len(rows) == 0:
+            continue
+        ip = piece.indptr
+        lens.append(ip[rows + 1] - ip[rows])
+        cut = np.flatnonzero(np.diff(rows) != 1) + 1
+        run_lo = ip[rows[np.concatenate(([0], cut))]].tolist()
+        run_hi = ip[rows[np.concatenate((cut, [len(rows)])) - 1] + 1].tolist()
+        data += [piece.data[a:z] for a, z in zip(run_lo, run_hi)]
+        indices += [piece.indices[a:z] for a, z in zip(run_lo, run_hi)]
+        for k, parts in obs.items():
+            parts.append(piece.obs[k][rows])
+    lens_all = np.concatenate(lens)
+    indptr = np.zeros(len(lens_all) + 1, dtype=np.int64)
+    np.cumsum(lens_all, out=indptr[1:])
+
+    def join(parts):
+        dtype = np.result_type(*(p.dtype for p in parts))
+        return np.concatenate(parts, out=pool.empty(int(indptr[-1]), dtype))
+
+    return CSRBatch(
+        data=join(data),
+        indices=join(indices),
+        indptr=indptr,
+        n_var=n_var,
+        obs={k: np.concatenate(parts) for k, parts in obs.items()},
+    )
+
+
+def gathered_nbytes(ranges: Sequence[tuple[CSRBatch, int, int]]) -> int:
+    """``gather_rows(...).nbytes`` for rows ``[lo, hi)`` of each ``(piece,
+    lo, hi)``, read off the pieces' ``indptr`` without copying."""
+    nnz = sum(int(p.indptr[hi] - p.indptr[lo]) for p, lo, hi in ranges)
+    rows = sum(hi - lo for _, lo, hi in ranges)
+    first = ranges[0][0]
+    return nnz * (first.data.itemsize + first.indices.itemsize) + (
+        rows + 1
+    ) * np.dtype(np.int64).itemsize
 
 
 def _concat_batches(batches: Sequence[CSRBatch], n_var: int) -> CSRBatch:

@@ -37,7 +37,13 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .backend import CSRCompositeAdapter, StorageAdapter, register_backend
-from .csr_store import CSRBatch, _concat_batches
+from .csr_store import (
+    BufferPool,
+    CSRBatch,
+    _concat_batches,
+    gather_rows,
+    gathered_nbytes,
+)
 
 __all__ = ["H5adStore", "H5adAdapter", "ShardedH5adAdapter"]
 
@@ -201,18 +207,26 @@ class H5adStore:
     def avg_row_bytes(self) -> float:
         return self._row_bytes
 
-    def read_range(self, start: int, stop: int) -> CSRBatch:
+    def read_range(self, start: int, stop: int, pool: BufferPool) -> CSRBatch:
         """ONE contiguous read of rows ``[start, stop)`` — a single
         ``data``/``indices`` byte range each (the planner's physical-read
-        primitive; no stats recording here)."""
+        primitive; no stats recording here), into ``pool``'s buffers."""
         lo, hi = int(self._indptr[start]), int(self._indptr[stop])
         return CSRBatch(
-            data=np.asarray(self._data[lo:hi], dtype=np.float32),
-            indices=np.asarray(self._indices[lo:hi]),
+            data=self._read_into(pool, self._data, lo, hi, np.float32),
+            indices=self._read_into(pool, self._indices, lo, hi, self._indices.dtype),
             indptr=self._indptr[start:stop + 1].astype(np.int64) - lo,
             n_var=self.n_var,
             obs={k: v[start:stop] for k, v in self._obs.items()},
         )
+
+    def _read_into(self, pool: BufferPool, dataset, lo: int, hi: int, dtype) -> np.ndarray:
+        out = pool.empty(hi - lo, dtype)
+        if self.driver == "h5py" and hi > lo:
+            dataset.read_direct(out, np.s_[lo:hi])
+        else:
+            out[:] = dataset[lo:hi]
+        return out
 
     def close(self) -> None:
         self._f.close()
@@ -223,18 +237,28 @@ class H5adAdapter(StorageAdapter):
 
     def __init__(self, store: H5adStore):
         self.store = store
+        self.pool = BufferPool()  # read extents and gathered batches
 
     def __len__(self) -> int:
         return len(self.store)
 
     def read_range(self, start: int, stop: int) -> CSRBatch:
-        return self.store.read_range(start, stop)
+        return self.store.read_range(start, stop, self.pool)
 
     def take(self, piece: CSRBatch, rows: np.ndarray) -> CSRBatch:
         return piece[rows]
 
     def concat(self, pieces: Sequence[CSRBatch]) -> CSRBatch:
         return _concat_batches(list(pieces), self.store.n_var)
+
+    def gather(self, sources: Sequence[tuple[CSRBatch, np.ndarray]]) -> CSRBatch:
+        return gather_rows(sources, self.store.n_var, self.pool)
+
+    def gather_nbytes(self, ranges: Sequence[tuple[CSRBatch, int, int]]) -> int:
+        return gathered_nbytes(ranges)
+
+    def end_fetch(self) -> None:
+        self.pool.trim()
 
     def nbytes_of(self, rows: np.ndarray) -> int:
         rows = np.asarray(rows, dtype=np.int64)

@@ -93,6 +93,7 @@ class PendingIO:
     breaker_closes: int = 0  # guarded-by: _lock
     reissued_fetches: int = 0  # guarded-by: _lock
     shared_rank_hits: int = 0  # guarded-by: _lock
+    blocks_cut: int = 0  # guarded-by: _lock
     div_batches: int = 0  # guarded-by: _lock
     div_entropy_sum: float = 0.0  # guarded-by: _lock
     div_entropy_min: float = 0.0  # guarded-by: _lock — valid only when div_batches > 0
@@ -163,6 +164,14 @@ class IOStats:
     counts blocks one rank obtained from another co-located rank's read —
     the RINAS-style cross-rank dedup win, measurable against ``requests``.
 
+    ``blocks_cut`` counts missed blocks a demand fetch cut out of its read
+    extents as values of their own: those a rendezvous claimant waits on,
+    and those the block cache still holds when the fetch ends.  The rest
+    of a fetch's missed rows go from the extents straight into the batch,
+    so ``blocks_cut / cache_misses`` is the share of missed blocks that
+    paid for a copy.  Readahead staging cuts every block it reads and is
+    not counted.
+
     The diversity counters are the loader's live §3.4 observatory:
     ``div_batches`` counts minibatches whose label entropy was observed
     (a :class:`~repro.core.dataset.ScDataset` built with ``diversity_obs``
@@ -193,6 +202,7 @@ class IOStats:
     breaker_closes: int = 0  # guarded-by: _lock — breakers closed by a probe
     reissued_fetches: int = 0  # guarded-by: _lock — suspect-rank fetches re-issued
     shared_rank_hits: int = 0  # guarded-by: _lock — blocks served by another rank's read
+    blocks_cut: int = 0  # guarded-by: _lock — missed blocks given a value of their own
     div_batches: int = 0  # guarded-by: _lock — batches with observed entropy
     div_entropy_sum: float = 0.0  # guarded-by: _lock — summed batch bits
     div_entropy_min: float = 0.0  # guarded-by: _lock — worst batch; valid iff div_batches > 0
@@ -220,6 +230,7 @@ class IOStats:
     spec_breaker_closes: int = 0  # guarded-by: _lock
     spec_reissued_fetches: int = 0  # guarded-by: _lock
     spec_shared_rank_hits: int = 0  # guarded-by: _lock
+    spec_blocks_cut: int = 0  # guarded-by: _lock
     spec_div_batches: int = 0  # guarded-by: _lock
     spec_div_entropy_sum: float = 0.0  # guarded-by: _lock
     spec_div_entropy_min: float = 0.0  # guarded-by: _lock
@@ -248,6 +259,7 @@ class IOStats:
         adm_bypassed: int = 0,
         adm_rejected: int = 0,
         shared_rank_hits: int = 0,
+        blocks_cut: int = 0,
         calls: int = 1,
         slept: bool = False,
     ) -> None:
@@ -272,6 +284,7 @@ class IOStats:
                 pend.adm_bypassed += adm_bypassed
                 pend.adm_rejected += adm_rejected
                 pend.shared_rank_hits += shared_rank_hits
+                pend.blocks_cut += blocks_cut
                 pend.wall_s += wall_s
                 pend.modeled_s += dt
         elif getattr(self._tl, "scope", None) is not None:
@@ -280,7 +293,7 @@ class IOStats:
                 cache_hits=cache_hits, cache_misses=cache_misses,
                 prefetched=prefetched, adm_bypassed=adm_bypassed,
                 adm_rejected=adm_rejected, shared_rank_hits=shared_rank_hits,
-                calls=calls, slept=slept,
+                blocks_cut=blocks_cut, calls=calls, slept=slept,
             )
             return  # the scoped child slept the simulated latency already
         else:
@@ -295,6 +308,7 @@ class IOStats:
                 self.adm_bypassed += adm_bypassed
                 self.adm_rejected += adm_rejected
                 self.shared_rank_hits += shared_rank_hits
+                self.blocks_cut += blocks_cut
                 self.wall_s += wall_s
                 self.modeled_s += dt
         # sleep OUTSIDE the lock: simulated latency must overlap across
@@ -581,6 +595,7 @@ class IOStats:
             self.retries = self.hedges_issued = self.hedges_won = 0
             self.breaker_opens = self.breaker_closes = 0
             self.reissued_fetches = self.shared_rank_hits = 0
+            self.blocks_cut = 0
             self.div_batches = 0
             self.div_entropy_sum = self.div_entropy_min = 0.0
             self.wall_s = self.modeled_s = self.request_wait_s = 0.0
@@ -594,6 +609,7 @@ class IOStats:
             self.spec_hedges_won = 0
             self.spec_breaker_opens = self.spec_breaker_closes = 0
             self.spec_reissued_fetches = self.spec_shared_rank_hits = 0
+            self.spec_blocks_cut = 0
             self.spec_div_batches = 0
             self.spec_div_entropy_sum = self.spec_div_entropy_min = 0.0
             self.spec_request_wait_s = self.spec_retry_wait_s = 0.0
@@ -630,6 +646,7 @@ class IOStats:
                 "breaker_closes": self.breaker_closes,
                 "reissued_fetches": self.reissued_fetches,
                 "shared_rank_hits": self.shared_rank_hits,
+                "blocks_cut": self.blocks_cut,
                 "div_batches": self.div_batches,
                 "div_entropy_sum": self.div_entropy_sum,
                 "div_entropy_min": self.div_entropy_min,
@@ -654,6 +671,7 @@ class IOStats:
                 "spec_breaker_closes": self.spec_breaker_closes,
                 "spec_reissued_fetches": self.spec_reissued_fetches,
                 "spec_shared_rank_hits": self.spec_shared_rank_hits,
+                "spec_blocks_cut": self.spec_blocks_cut,
                 "spec_div_batches": self.spec_div_batches,
                 "spec_div_entropy_sum": self.spec_div_entropy_sum,
                 "spec_div_entropy_min": self.spec_div_entropy_min,

@@ -250,20 +250,62 @@ class BlockCache:
                 self.cur_bytes -= ent[1]
 
     def put(self, key, value, nbytes: int) -> None:
-        nbytes = int(nbytes)
-        if self.max_bytes <= 0 or nbytes > self.max_bytes:
+        self.put_many([(key, value, nbytes)])
+
+    def survivors(self, sizes: Sequence[int]) -> list[bool]:
+        """Which of values of these byte sizes, handed to :meth:`put_many`
+        in this order, the cache still holds when it returns.
+
+        Under LRU the newest values evict the older ones, so the survivors
+        are the longest suffix of the sequence whose bytes fit
+        ``max_bytes`` together, leaving out values too large to cache at
+        all (they are never inserted).  Entries already resident do not
+        change the answer: they are evicted before any of the new values.
+        A caller learns this way which values it need not build.
+        """
+        keep = [False] * len(sizes)
+        if self.max_bytes <= 0:
+            return keep
+        room = self.max_bytes
+        for i in range(len(sizes) - 1, -1, -1):
+            nbytes = int(sizes[i])
+            if nbytes > self.max_bytes:
+                continue
+            if nbytes > room:
+                break
+            room -= nbytes
+            keep[i] = True
+        return keep
+
+    def put_many(self, items: Sequence[tuple[Any, Any, int]]) -> None:
+        """:meth:`put` each ``(key, value, nbytes)`` in order, under one hold
+        of the lock, so no other thread's insertion falls in between.
+
+        A value :meth:`survivors` says the cache will not hold afterwards
+        may be None: it is inserted and evicted within the call, and
+        counted so, exactly as a real one would be, but no reader can see
+        it.  A None the cache would keep raises ``ValueError``.
+        """
+        items = [(key, value, int(nbytes)) for key, value, nbytes in items]
+        keep = self.survivors([nbytes for _, _, nbytes in items])
+        if any(value is None and kept for (_, value, _), kept in zip(items, keep)):
+            raise ValueError("put_many: a value the cache keeps is None")
+        if self.max_bytes <= 0:
             return
         with self._lock:
-            if key in self._entries:
-                _, old = self._entries.pop(key)
-                self.cur_bytes -= old
-            while self._entries and self.cur_bytes + nbytes > self.max_bytes:
-                _, (_, old) = self._entries.popitem(last=False)
-                self.cur_bytes -= old
-                self.evictions += 1
-            self._entries[key] = (value, nbytes)
-            self.cur_bytes += nbytes
-            self.insertions += 1
+            for key, value, nbytes in items:
+                if nbytes > self.max_bytes:
+                    continue
+                if key in self._entries:
+                    _, old = self._entries.pop(key)
+                    self.cur_bytes -= old
+                while self._entries and self.cur_bytes + nbytes > self.max_bytes:
+                    _, (_, old) = self._entries.popitem(last=False)
+                    self.cur_bytes -= old
+                    self.evictions += 1
+                self._entries[key] = (value, nbytes)
+                self.cur_bytes += nbytes
+                self.insertions += 1
 
     def put_admit(self, key, value, nbytes: int, estimate) -> bool:
         """TinyLFU-guarded insertion: evict only victims *colder* than the
@@ -568,6 +610,21 @@ class SegmentedBlockCache(BlockCache):
             return
         with self._lock:
             self._insert(key, value, nbytes, None)
+
+    def survivors(self, sizes: Sequence[int]) -> list[bool]:
+        """Every value the cache could take at all: which of them survive
+        the window's admission duels is not known without running them."""
+        return [0 < self.max_bytes and int(nbytes) <= self.max_bytes
+                for nbytes in sizes]
+
+    def put_many(self, items: Sequence[tuple[Any, Any, int]]) -> None:
+        keep = self.survivors([nbytes for _, _, nbytes in items])
+        for (key, value, nbytes), kept in zip(items, keep):
+            if value is None:
+                if kept:
+                    raise ValueError("put_many: a value the cache may keep is None")
+                continue  # too large to cache: put() would refuse it
+            self.put(key, value, nbytes)
 
     def put_admit(self, key, value, nbytes: int, estimate) -> bool:
         """Frequency-guarded insertion; see the class docstring.  Returns
