@@ -44,6 +44,10 @@ __all__ = [
 ]
 
 
+# glibc's largest mmap threshold on 64-bit: an array this large is always a
+# fresh mapping, whose pages fault on first touch
+DENSE_POOL_BYTES = 32 << 20
+
 # Reuse by reference count needs a count that only the GIL keeps exact.
 _REFCOUNTS_SHOW_USE = sys.implementation.name == "cpython" and getattr(
     sys, "_is_gil_enabled", lambda: True
@@ -58,13 +62,14 @@ class BufferPool:
     freed, so a fetch that reads its extents and gathers its batch into
     fresh arrays pays those faults again every time: on the TPU v5e host
     (gVisor) filling 256 MiB takes ~290 ms fresh against ~124 ms warm.  The
-    pool keeps such buffers.  A buffer is free again when CPython's
-    reference count shows that no array is a view of it (every view of a
-    view refers to the buffer itself), so callers never give anything
-    back.  That assumes CPython with the GIL, and that whatever reads a
-    batch's memory holds a Python reference to it, as NumPy views and the
-    buffer protocol do; on any other interpreter the pool hands out fresh
-    arrays.
+    pool keeps such buffers, and the large dense batches
+    :meth:`CSRBatch.to_dense` makes of a fetch's rows.  A buffer is free
+    again when CPython's reference count shows that no array is a view of
+    it (every view of a view refers to the buffer itself), so callers never
+    give anything back.  That assumes CPython with the GIL, and that
+    whatever reads a batch's memory holds a Python reference to it, as
+    NumPy views and the buffer protocol do; on any other interpreter the
+    pool hands out fresh arrays.
 
     Bounds: the free buffers never hold more bytes than were ever in use
     at once, and :meth:`trim`, which ends a window, cuts them to the most
@@ -162,6 +167,9 @@ class CSRBatch:
     indptr: np.ndarray  # (rows+1,) int64
     n_var: int
     obs: dict  # column -> (rows,) array
+    # where ``to_dense`` takes its output from; the batch a fetch gathers,
+    # and the rows taken from it, carry their adapter's pool
+    pool: Optional["BufferPool"] = dataclasses.field(default=None, repr=False, compare=False)
 
     def __len__(self) -> int:
         return len(self.indptr) - 1
@@ -182,14 +190,26 @@ class CSRBatch:
             indptr=new_indptr,
             n_var=self.n_var,
             obs={k: v[rows] for k, v in self.obs.items()},
+            pool=self.pool,
         )
 
     def to_dense(self) -> np.ndarray:
         """Dense (rows, n_var).  Assumes canonical CSR (unique columns per
         row, as AnnData guarantees) — duplicate columns would overwrite, not
-        accumulate; ``to_ell`` + the Pallas kernel accumulate."""
+        accumulate; ``to_ell`` + the Pallas kernel accumulate.
+
+        An output of :data:`DENSE_POOL_BYTES` or more (256 rows of 62,710
+        genes are 64 MB) is one of :attr:`pool`'s buffers, where the batch
+        has a pool: glibc maps every array that large afresh, so a fresh
+        output would pay a page fault per page on every call.  A smaller
+        one comes back warm from malloc's heap."""
         with span("scdataset.to_dense", rows=len(self)):
-            out = np.zeros((len(self), self.n_var), dtype=np.float32)
+            shape = (len(self), self.n_var)
+            if self.pool is None or shape[0] * shape[1] * 4 < DENSE_POOL_BYTES:
+                out = np.zeros(shape, dtype=np.float32)
+            else:
+                out = self.pool.empty(shape[0] * shape[1], np.float32).reshape(shape)
+                out.fill(0)
             rows = np.repeat(
                 np.arange(len(self)), np.diff(self.indptr).astype(np.int64)
             )
@@ -482,6 +502,7 @@ def gather_rows(
         indptr=indptr,
         n_var=n_var,
         obs={k: np.concatenate(parts) for k, parts in obs.items()},
+        pool=pool,
     )
 
 

@@ -43,12 +43,16 @@ def put_batch(
     rules: Rules,
     axes: Optional[Mapping[str, tuple]] = None,
 ) -> dict:
-    """device_put every leaf with its resolved NamedSharding."""
-    with span("scdataset.put_batch"):
+    """device_put every leaf with its resolved NamedSharding.
+
+    Its span, ``scdataset.put_batch``, carries the number of this host's
+    devices the batch is laid over (``devices``) and the batch's bytes."""
+    batch = {k: np.asarray(v) for k, v in batch.items()}
+    nbytes = sum(v.nbytes for v in batch.values())
+    with span("scdataset.put_batch", devices=len(mesh.local_devices), bytes=nbytes):
         axes = axes or batch_axes_for(batch)
         out = {}
         for k, v in batch.items():
-            v = np.asarray(v)
             sh = sharding_for_axes(axes[k], rules, mesh, v.shape)
             if jax.process_count() > 1:  # pragma: no cover (multi-host path)
                 out[k] = jax.make_array_from_process_local_data(sh, v)

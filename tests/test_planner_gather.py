@@ -24,6 +24,7 @@ from repro.data import (
     write_h5ad,
 )
 from repro.data.backend import PlannedCollection, StorageAdapter, open_adapter
+from repro.data import csr_store
 from repro.data.csr_store import BufferPool, ShardedCSRStore
 from repro.data.h5ad import _HAVE_H5PY
 from repro.data.readplan import BlockCache, SegmentedBlockCache
@@ -461,3 +462,28 @@ def test_pooled_buffers_never_overwrite_what_is_held(wide):
     del held  # now the pool hands the batches' buffers out again
     for rows in fetches[:3]:
         _assert_same(coll.fetch(rows), store[rows])
+
+
+def test_dense_batches_come_from_the_pool_and_are_zeroed(wide, monkeypatch):
+    """``to_dense`` of a fetched batch takes a large output from the
+    adapter's pool, never a buffer still held, and zeroes a reused one;
+    a small one, or one of a batch without a pool, is a fresh array."""
+    store = ShardedCSRStore([os.path.join(wide, p) for p in ("p0", "p1")])
+    coll = open_collection(f"sharded-csr://{wide}", block_rows=B, cache_bytes=2 << 20)
+    rows = np.sort(np.random.default_rng(9).choice(len(store), 900, replace=False))
+    fetched = coll.fetch(rows)
+    assert fetched.pool is coll.adapter.pool
+    parts = [np.arange(k, 900, 3) for k in range(3)]  # 300 rows: 4.8 MB dense
+    assert fetched[parts[0]].to_dense().base is None  # below DENSE_POOL_BYTES
+    monkeypatch.setattr(csr_store, "DENSE_POOL_BYTES", 4 << 20)
+    a = fetched[parts[0]].to_dense()
+    b = fetched[parts[1]].to_dense()
+    assert a.base is not None and a.base is not b.base
+    base = id(a.base)  # an id: a reference would keep the buffer in use
+    np.testing.assert_array_equal(a, store[rows[parts[0]]].to_dense())
+    del a
+    c = fetched[parts[2]].to_dense()
+    assert id(c.base) == base
+    np.testing.assert_array_equal(c, store[rows[parts[2]]].to_dense())
+    np.testing.assert_array_equal(b, store[rows[parts[1]]].to_dense())
+    assert store[rows[:300]].to_dense().base is None  # a batch without a pool

@@ -135,10 +135,40 @@ def test_to_dense_and_put_batch_spans(tmp_path):
         return x, put_batch({"x": x}, mesh, RULES_TRAIN)
 
     (x, dev), spans = _traced(tmp_path, run)
-    assert [(s[0], s[4]) for s in spans] == [("scdataset.to_dense", {"rows": 6}),
-                                             ("scdataset.put_batch", {})]
+    assert [(s[0], s[4]) for s in spans] == [
+        ("scdataset.to_dense", {"rows": 6}),
+        ("scdataset.put_batch", {"devices": 1, "bytes": x.nbytes})]
     np.testing.assert_array_equal(np.asarray(dev["x"]), x)
     assert x.sum() == data.sum()
+
+
+def test_put_batch_span_counts_the_devices_of_a_four_device_mesh(tmp_path):
+    """On a mesh of four devices the span says four, and the bytes are the
+    global batch's, which the four devices share."""
+    script = f"""
+import glob, json, sys
+sys.path[:0] = [{os.path.join(ROOT, "src")!r}]
+import jax
+import numpy as np
+from jax.profiler import ProfileData
+from repro.distributed.dataio import put_batch
+from repro.distributed.sharding import RULES_TRAIN
+mesh = jax.make_mesh((4,), ("data",))
+batch = {{"x": np.ones((8, 5), np.float32), "y": np.arange(8, dtype=np.int32)}}
+with jax.profiler.trace({str(tmp_path)!r}):
+    dev = put_batch(batch, mesh, RULES_TRAIN)
+path = glob.glob({str(tmp_path)!r} + "/plugins/profile/*/*.xplane.pb")[0]
+args = [dict(e.stats) for p in ProfileData.from_file(path).planes for l in p.lines
+        for e in l.events if e.name == "scdataset.put_batch"]
+print(json.dumps({{"args": args, "devices": len(dev["x"].sharding.device_set)}}))
+"""
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env.update(JAX_PLATFORMS="cpu", XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", script], env=env, capture_output=True,
+                       text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    assert json.loads(p.stdout.strip().splitlines()[-1]) == {
+        "args": [{"devices": 4, "bytes": 8 * 5 * 4 + 8 * 4}], "devices": 4}
 
 
 def test_span_is_a_trace_annotation_once_jax_is_imported():
