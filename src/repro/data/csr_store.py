@@ -26,6 +26,8 @@ import os
 import sys
 import threading
 import time
+from concurrent import futures
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Sequence
 
 import numpy as np
@@ -47,6 +49,12 @@ __all__ = [
 # glibc's largest mmap threshold on 64-bit: an array this large is always a
 # fresh mapping, whose pages fault on first touch
 DENSE_POOL_BYTES = 32 << 20
+
+# ``to_dense`` gives each thread at least this much output to zero and fill,
+# so an output below twice this stays on the calling thread
+DENSE_CHUNK_BYTES = 2 << 20
+# the most threads, the calling one included, that densify one batch
+DENSE_WORKERS = 8
 
 # Reuse by reference count needs a count that only the GIL keeps exact.
 _REFCOUNTS_SHOW_USE = sys.implementation.name == "cpython" and getattr(
@@ -158,7 +166,8 @@ class CSRBatch:
     Supports row indexing so it can flow through ScDataset's in-memory
     reshuffle/batching (Algorithm 1 lines 9–10) without densification;
     ``to_dense`` is the fetch_transform hot-spot.  Densify runs on the host
-    today; the on-chip path (``to_ell`` + repro.kernels.csr_to_dense) is
+    today, a large batch's rows split over threads that each zero and fill
+    their own; the on-chip path (``to_ell`` + repro.kernels.csr_to_dense) is
     checked on the TPU only by ``chip_smoke.py``.
     """
 
@@ -194,26 +203,44 @@ class CSRBatch:
         )
 
     def to_dense(self) -> np.ndarray:
-        """Dense (rows, n_var).  Assumes canonical CSR (unique columns per
-        row, as AnnData guarantees) — duplicate columns would overwrite, not
-        accumulate; ``to_ell`` + the Pallas kernel accumulate.
+        """Dense (rows, n_var) float32.  Assumes canonical CSR (unique
+        columns per row, as AnnData guarantees) — duplicate columns would
+        overwrite, the last one winning, not accumulate; ``to_ell`` + the
+        Pallas kernel accumulate.
+
+        The rows are split into contiguous chunks of at least
+        :data:`DENSE_CHUNK_BYTES` of output, one a thread, up to
+        :data:`DENSE_WORKERS` and the CPUs this process may run on; each
+        thread zeroes its chunk and scatters the chunk's nonzeros into it
+        while it is still in cache.  A smaller output stays on the calling
+        thread.  The span's ``workers`` says how many threads took part.
 
         An output of :data:`DENSE_POOL_BYTES` or more (256 rows of 62,710
         genes are 64 MB) is one of :attr:`pool`'s buffers, where the batch
         has a pool: glibc maps every array that large afresh, so a fresh
         output would pay a page fault per page on every call.  A smaller
         one comes back warm from malloc's heap."""
-        with span("scdataset.to_dense", rows=len(self)):
-            shape = (len(self), self.n_var)
-            if self.pool is None or shape[0] * shape[1] * 4 < DENSE_POOL_BYTES:
-                out = np.zeros(shape, dtype=np.float32)
+        n, n_var = len(self), self.n_var
+        workers = _dense_workers(n, n * n_var * 4)
+        with span("scdataset.to_dense", rows=n, workers=workers):
+            if self.pool is None or n * n_var * 4 < DENSE_POOL_BYTES:
+                out = np.empty((n, n_var), np.float32)
             else:
-                out = self.pool.empty(shape[0] * shape[1], np.float32).reshape(shape)
-                out.fill(0)
-            rows = np.repeat(
-                np.arange(len(self)), np.diff(self.indptr).astype(np.int64)
-            )
-            out[rows, self.indices.astype(np.int64)] = self.data
+                out = self.pool.empty(n * n_var, np.float32).reshape(n, n_var)
+            # the workers reach the output only through this list, which is
+            # emptied before returning: a work item the executor has not yet
+            # let go of must not keep the pool's buffer in use
+            job = [out.reshape(-1), self.data, self.indices, self.indptr, n_var]
+            bounds = [n * k // workers for k in range(workers + 1)]
+            done = [_dense_executor().submit(_fill_rows, job, lo, hi)
+                    for lo, hi in zip(bounds[1:-1], bounds[2:])]
+            try:
+                _fill_rows(job, bounds[0], bounds[1])
+            finally:
+                futures.wait(done)
+                job.clear()
+            for f in done:
+                f.result()
             return out
 
     def to_ell(self, k_max: Optional[int] = None) -> tuple[np.ndarray, np.ndarray]:
@@ -246,6 +273,54 @@ class CSRBatch:
     @property
     def nbytes(self) -> int:
         return int(self.data.nbytes + self.indices.nbytes + self.indptr.nbytes)
+
+
+def _fill_rows(job: list, lo: int, hi: int) -> None:
+    """Zero rows ``[lo, hi)`` of ``to_dense``'s flat output, then scatter
+    their nonzeros into them by flat offset (``row * n_var + col``)."""
+    out, data, indices, indptr, n_var = job
+    part = out[lo * n_var:hi * n_var]
+    part.fill(0)
+    s, e = int(indptr[lo]), int(indptr[hi])
+    cols = indices[s:e]
+    if e > s and not (cols.min() >= 0 and cols.max() < n_var):
+        raise IndexError(f"a column index of rows [{lo}, {hi}) is outside [0, {n_var})")
+    offsets = cols.astype(np.int64)
+    offsets += np.repeat(np.arange(0, (hi - lo) * n_var, n_var, dtype=np.int64),
+                         np.diff(indptr[lo:hi + 1]))
+    part[offsets] = data[s:e]
+
+
+def _dense_workers(rows: int, nbytes: int) -> int:
+    """Threads that densify an output of ``rows`` rows and ``nbytes``."""
+    workers = min(DENSE_WORKERS, rows, nbytes // DENSE_CHUNK_BYTES)
+    if workers > 1:
+        workers = min(workers, len(os.sched_getaffinity(0)))
+    return max(1, workers)
+
+
+_dense_lock = threading.Lock()
+_dense_pool: Optional[ThreadPoolExecutor] = None
+
+
+def _dense_executor() -> ThreadPoolExecutor:
+    """The one thread pool every ``to_dense`` shares, made on first use."""
+    global _dense_pool
+    with _dense_lock:
+        if _dense_pool is None:
+            _dense_pool = ThreadPoolExecutor(
+                DENSE_WORKERS - 1, thread_name_prefix="scdataset-densify")
+        return _dense_pool
+
+
+def _forget_dense_executor() -> None:
+    """In a forked child: the parent's threads are not there."""
+    global _dense_lock, _dense_pool
+    _dense_lock = threading.Lock()
+    _dense_pool = None
+
+
+os.register_at_fork(after_in_child=_forget_dense_executor)
 
 
 def _ranges_concat(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:

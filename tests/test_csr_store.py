@@ -1,9 +1,15 @@
 """On-disk CSR store: correctness vs dense reference, run counting, sharding."""
+import sys
+import threading
+
 import numpy as np
 import pytest
 
-from repro.data import CSRStore, ShardedCSRStore, write_csr_shard
-from repro.data.csr_store import _ranges_concat, _within_run_positions
+from repro.data import CSRBatch, CSRStore, ShardedCSRStore, write_csr_shard
+from repro.data import csr_store
+from repro.data.csr_store import BufferPool, _ranges_concat, _within_run_positions
+
+TAHOE_GENES = 62_710
 
 
 def _random_csr(rng, n, g, max_nnz=12):
@@ -50,6 +56,129 @@ def test_duplicates_and_order_preserved(shard):
     got = store[rows]
     assert np.allclose(got.to_dense(), dense[rows])
     assert np.array_equal(got.obs["row"], rows)
+
+
+def _wide_batch(rng, n, g, max_nnz=300, empty=(), duplicates=False, pool=None):
+    """A batch of ``n`` rows of ``g`` columns, the rows in ``empty`` without
+    nonzeros; with ``duplicates`` a row may name a column more than once."""
+    lens = rng.integers(1, max_nnz, n)
+    lens[list(empty)] = 0
+    indptr = np.zeros(n + 1, np.int64)
+    np.cumsum(lens, out=indptr[1:])
+    indices = np.concatenate(
+        [np.empty(0, np.int64)]
+        + [rng.choice(g, int(k), replace=duplicates) for k in lens]).astype(np.int32)
+    data = rng.normal(0, 1, int(indptr[-1])).astype(np.float32)
+    return CSRBatch(data, indices, indptr, g, {}, pool=pool)
+
+
+def _dense_row_by_row(b):
+    """The dense batch, one nonzero at a time: the last of a column wins."""
+    out = np.zeros((len(b), b.n_var), np.float32)
+    for i in range(len(b)):
+        for j in range(b.indptr[i], b.indptr[i + 1]):
+            out[i, b.indices[j]] = b.data[j]
+    return out
+
+
+@pytest.mark.parametrize("rows, g, chunk_bytes, empty, duplicates, pooled, workers", [
+    pytest.param(1, TAHOE_GENES, None, (), False, False, 1, id="1-row"),
+    pytest.param(3, 32, None, (), False, False, 1, id="3-rows"),
+    pytest.param(3, TAHOE_GENES, 256 << 10, (), False, False, 2, id="3-rows-in-2-chunks"),
+    pytest.param(64, TAHOE_GENES, None, (), False, False, 7, id="64-rows"),
+    pytest.param(257, TAHOE_GENES, None, (), False, False, 8, id="257-rows"),
+    pytest.param(257, TAHOE_GENES, None, (), False, True, 8, id="257-rows-pooled"),
+    pytest.param(40, TAHOE_GENES, None, (0, 39, *range(10, 20)), False, False, 4,
+                 id="empty-rows"),
+    pytest.param(16, TAHOE_GENES, None, range(16), False, False, 1, id="all-rows-empty"),
+    pytest.param(0, TAHOE_GENES, None, (), False, False, 1, id="no-rows"),
+    pytest.param(12, 50, 200, (), True, False, 8, id="duplicate-columns"),
+])
+def test_to_dense_equals_a_row_by_row_reference(monkeypatch, rows, g, chunk_bytes, empty,
+                                                duplicates, pooled, workers):
+    """``to_dense`` on one thread or on several, from the heap or from a
+    pool, is bit for bit the batch built one nonzero at a time."""
+    monkeypatch.setattr(csr_store.os, "sched_getaffinity", lambda pid: set(range(8)))
+    monkeypatch.setattr(csr_store, "DENSE_WORKERS", 8)
+    if chunk_bytes is not None:
+        monkeypatch.setattr(csr_store, "DENSE_CHUNK_BYTES", chunk_bytes)
+    rng = np.random.default_rng(rows)
+    b = _wide_batch(rng, rows, g, max_nnz=min(300, g), empty=empty,
+                    duplicates=duplicates, pool=BufferPool() if pooled else None)
+    assert csr_store._dense_workers(rows, rows * g * 4) == workers
+    got = b.to_dense()
+    assert got.dtype == np.float32 and got.shape == (rows, g)
+    assert (got.base is not None) == pooled
+    if pooled:
+        assert got.nbytes >= csr_store.DENSE_POOL_BYTES
+    np.testing.assert_array_equal(got.view(np.uint32), _dense_row_by_row(b).view(np.uint32))
+
+
+@pytest.mark.parametrize("chunk_bytes", [None, 64], ids=["one-thread", "four-threads"])
+@pytest.mark.parametrize("col", [-1, 50])
+def test_to_dense_refuses_a_column_outside_the_batch(monkeypatch, chunk_bytes, col):
+    """A flat offset would put such a column into a neighbouring row."""
+    monkeypatch.setattr(csr_store.os, "sched_getaffinity", lambda pid: set(range(4)))
+    if chunk_bytes is not None:
+        monkeypatch.setattr(csr_store, "DENSE_CHUNK_BYTES", chunk_bytes)
+    b = _wide_batch(np.random.default_rng(4), 8, 50, max_nnz=20)
+    b.indices[b.indptr[4]] = col  # the first nonzero of the third chunk's first row
+    with pytest.raises(IndexError, match="outside"):
+        b.to_dense()
+
+
+def test_threaded_to_dense_hands_its_pool_buffer_back_every_time(monkeypatch):
+    """No worker keeps a view of a pooled output: each call, once the last
+    result is gone, gets the same buffer back, zeroed afresh by the workers."""
+    monkeypatch.setattr(csr_store.os, "sched_getaffinity", lambda pid: set(range(4)))
+    rows = -(-csr_store.DENSE_POOL_BYTES // (TAHOE_GENES * 4))
+    b = _wide_batch(np.random.default_rng(5), rows, TAHOE_GENES, pool=BufferPool())
+    assert csr_store._dense_workers(rows, rows * TAHOE_GENES * 4) == 4
+    want = _dense_row_by_row(b)
+    x = b.to_dense()
+    base = id(x.base)  # an id: a reference would keep the buffer in use
+    for _ in range(20):
+        np.testing.assert_array_equal(x, want)
+        x.fill(np.nan)
+        del x
+        x = b.to_dense()
+        assert id(x.base) == base
+
+
+def test_to_dense_from_many_threads_at_once(monkeypatch):
+    """Threads that densify pooled batches at once, more of them than the
+    CPUs, share the one executor and the pool: every result is its own
+    batch's, and stays so while the thread's next call runs."""
+    monkeypatch.setattr(csr_store.os, "sched_getaffinity", lambda pid: set(range(4)))
+    monkeypatch.setattr(csr_store, "DENSE_POOL_BYTES", 4 << 20)
+    pool = BufferPool()
+    batches = [_wide_batch(np.random.default_rng(100 + t), 24, TAHOE_GENES, pool=pool)
+               for t in range(12)]
+    wants = [_dense_row_by_row(b) for b in batches]
+    wrong = []
+
+    def densify(t):
+        last = None
+        for _ in range(6):
+            x = batches[t].to_dense()
+            if last is not None and not np.array_equal(last, wants[t]):
+                wrong.append(t)
+            if not np.array_equal(x, wants[t]):
+                wrong.append(t)
+            last = x
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=densify, args=(t,)) for t in range(12)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(th.is_alive() for th in threads)
+    assert wrong == []
 
 
 def test_run_counting(shard):

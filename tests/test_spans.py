@@ -136,9 +136,27 @@ def test_to_dense_and_put_batch_spans(tmp_path):
 
     (x, dev), spans = _traced(tmp_path, run)
     assert [(s[0], s[4]) for s in spans] == [
-        ("scdataset.to_dense", {"rows": 6}),
+        ("scdataset.to_dense", {"rows": 6, "workers": 1}),
         ("scdataset.put_batch", {"devices": 1, "bytes": x.nbytes})]
     np.testing.assert_array_equal(np.asarray(dev["x"]), x)
+    assert x.sum() == data.sum()
+
+
+def test_to_dense_span_counts_the_threads_of_a_large_batch(tmp_path, monkeypatch):
+    """A batch of 64 rows of Tahoe's 62,710 genes (16 MB) is densified on
+    several threads, and the span says how many."""
+    from repro.data import csr_store
+
+    monkeypatch.setattr(csr_store.os, "sched_getaffinity", lambda pid: set(range(4)))
+    rng = np.random.default_rng(3)
+    n, g = 64, 62_710
+    indptr = np.arange(n + 1, dtype=np.int64) * 100
+    indices = np.concatenate([np.sort(rng.choice(g, 100, replace=False))
+                              for _ in range(n)]).astype(np.int32)
+    data = rng.integers(1, 9, n * 100).astype(np.float32)
+    x, spans = _traced(tmp_path, CSRBatch(data, indices, indptr, g, {}).to_dense)
+    assert [(s[0], s[4]) for s in spans] == [
+        ("scdataset.to_dense", {"rows": 64, "workers": 4})]
     assert x.sum() == data.sum()
 
 
